@@ -6,12 +6,13 @@ per-round host surface — Python dispatch, host RNG + batch conversion,
 the per-site device→host copy + numpy quantize/fold of the compressed
 loop — that gated the stacked simulator.
 
-Protocol: every timed variant is ONE fresh ``repro.launch.train``
-process (the way a user actually runs a 20-round job), so each engine
-pays its own real host-side cost profile; timing comes from the job's
-own artifact (``wall_s`` spans the round loop only, ``compile_s`` is
-the one-time jit compile measured separately since the ISSUE-4 timing
-fix).  Speedups compare ``wall_s − compile_s``:
+Protocol: every timed variant is one ``repro.launch.train`` run with
+its own CLI arguments, made in this process (a chip belongs to one
+process at a time, so the bench never spawns a JAX child behind a
+parent that may hold the chip); timing comes from the job's own result
+(``wall_s`` spans the round loop only, ``compile_s`` is the one-time
+jit compile measured separately).  Speedups compare
+``wall_s − compile_s``:
 
   * ``loop``        — the retired per-round driver (``--round-engine loop``)
   * ``scan``        — the compiled engine, host batches (one H2D per chunk)
@@ -33,32 +34,23 @@ dispatch/PCIe-bound regime the ISSUE targets applies to every path.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 import time
-from pathlib import Path
 
 from benchmarks.common import ARTIFACTS
-
-SRC = Path(__file__).resolve().parents[1] / "src"
 
 
 SITES, BATCH, SEQ = 8, 1, 8      # small config: overhead-dominated rounds
 
 
-def _run_cli(tmp: Path, name: str, rounds: int, extra) -> dict:
-    """One fresh training process; returns the job's own result JSON."""
-    out = tmp / name
-    argv = [sys.executable, "-m", "repro.launch.train",
-            "--arch", "smollm-135m", "--reduced", "--sites", str(SITES),
+def _run_cli(rounds: int, extra) -> dict:
+    """One training run through the train CLI's argument mapping, in
+    this process; returns the job's own result record."""
+    from repro.launch import train
+    argv = ["--arch", "smollm-135m", "--reduced", "--sites", str(SITES),
             "--batch", str(BATCH), "--seq", str(SEQ), "--het", "0.3",
-            "--rounds", str(rounds), "--quiet", "--out", str(out)] + extra
-    env = {**os.environ,
-           "PYTHONPATH": str(SRC) + os.pathsep + os.environ.get(
-               "PYTHONPATH", "")}
-    subprocess.run(argv, check=True, env=env)
-    rec = json.loads((out / "train_fedavg.json").read_text())
+            "--rounds", str(rounds), "--quiet"] + extra
+    rec = train.run(train.make_parser().parse_args(argv))
     exec_s = max(rec["wall_s"] - rec["compile_s"], 1e-9)
     return {"wall_s": rec["wall_s"], "compile_s": rec["compile_s"],
             "exec_s": exec_s,
@@ -90,24 +82,18 @@ def _chunk_sweep(rounds: int) -> dict:
 
 
 def run(quick: bool = False):
-    import tempfile
     rounds = 6 if quick else 20
-    with tempfile.TemporaryDirectory() as td:
-        tmp = Path(td)
-        loop = _run_cli(tmp, "loop", rounds, ["--round-engine", "loop"])
-        scan = _run_cli(tmp, "scan", rounds, ["--round-engine", "scan"])
-        scan_dev = _run_cli(tmp, "scan_dev", rounds,
-                            ["--round-engine", "scan", "--device-data"])
-        loop8 = _run_cli(tmp, "loop8", rounds,
-                         ["--round-engine", "loop", "--compression", "int8"])
-        scan8 = _run_cli(tmp, "scan8", rounds,
-                         ["--round-engine", "scan", "--compression", "int8"])
-        loop_buf = _run_cli(tmp, "loop_buf", rounds,
-                            ["--round-engine", "loop", "--scheduler",
-                             "buffered", "--buffer-k", "2"])
-        scan_buf = _run_cli(tmp, "scan_buf", rounds,
-                            ["--round-engine", "scan", "--scheduler",
-                             "buffered", "--buffer-k", "2"])
+    loop = _run_cli(rounds, ["--round-engine", "loop"])
+    scan = _run_cli(rounds, ["--round-engine", "scan"])
+    scan_dev = _run_cli(rounds, ["--round-engine", "scan", "--device-data"])
+    loop8 = _run_cli(rounds, ["--round-engine", "loop", "--compression",
+                              "int8"])
+    scan8 = _run_cli(rounds, ["--round-engine", "scan", "--compression",
+                              "int8"])
+    loop_buf = _run_cli(rounds, ["--round-engine", "loop", "--scheduler",
+                                 "buffered", "--buffer-k", "2"])
+    scan_buf = _run_cli(rounds, ["--round-engine", "scan", "--scheduler",
+                                 "buffered", "--buffer-k", "2"])
     sweep = _chunk_sweep(rounds)
 
     speedup_sync = loop["exec_s"] / min(scan["exec_s"], scan_dev["exec_s"])
@@ -119,9 +105,9 @@ def run(quick: bool = False):
     batch_h2d = SITES * BATCH * SEQ * 4           # int32 tokens, S·B·L
     out = {
         "bench": f"round_engine scan-vs-loop ({rounds}-round stacked "
-                 "fedavg, fresh process per variant)",
+                 "fedavg, one in-process run per variant)",
         "rounds": rounds, "sites": SITES,
-        "note": "Speedups are wall−compile, each variant a fresh process. "
+        "note": "Speedups are wall−compile, each variant its own run. "
                 "The sync-barrier path is bounded by this container's "
                 "2-core XLA compute floor (the loop and the scan run the "
                 "identical per-round program, so fusing rounds mostly "

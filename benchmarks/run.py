@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (agg_engine, comm_bytes, dose_prediction,
                             gossip_robustness, parallel_scaling, pod_scaling,
                             privacy_tradeoff, robust_agg, roofline,

@@ -1882,9 +1882,24 @@ class ThreadTransport(_SocketTransport):
 
 class TcpTransport(_SocketTransport):
     """Real TCP round trips, one OS process per site (paper §III.A.3:
-    sites identified by IP:port, colocated or spread across machines)."""
+    sites identified by IP:port, colocated or spread across machines).
+
+    A CPU-host transport: a TPU chip belongs to one process at a time,
+    and this process (the aggregation server) already holds it, so site
+    processes spawned here could not reach it.  On a TPU host it refuses
+    before spawning; the chip path is the stacked transport."""
 
     name = "tcp"
+
+    def execute(self, job: FederatedJob, rounds: int,
+                resume: bool = False) -> JobResult:
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "transport='tcp' runs one JAX process per site, but a TPU "
+                "chip belongs to one process at a time and this process "
+                "already holds it; use transport='stacked' on a TPU host, "
+                "or run the tcp transport on CPU hosts (JAX_PLATFORMS=cpu)")
+        return super().execute(job, rounds, resume)
 
     def _run_workers(self, job, num_sites, agg_addr, coord_addr, rounds,
                      start_round=0):

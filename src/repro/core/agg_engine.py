@@ -199,7 +199,7 @@ class AggregationEngine:
     the Pallas interpreter (bit-faithful to the TPU program) on CPU.
     """
 
-    def __init__(self, *, block_n: int = 65536,
+    def __init__(self, *, block_n: Optional[int] = None,
                  use_pallas: Optional[bool] = None,
                  interpret: Optional[bool] = None):
         self.block_n = block_n

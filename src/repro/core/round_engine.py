@@ -1073,7 +1073,6 @@ def execute_sharded(job, bundle, scheduler, codec, rounds: int,
     sharded run is the dense run (allclose; summation order differs
     across device blocks).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec
     from repro.launch.mesh import make_site_mesh
 
@@ -1254,8 +1253,9 @@ def execute_sharded(job, bundle, scheduler, codec, rounds: int,
             metrics["loss"].astype(jnp.float32), mode="drop")
         return c2, losses
 
-    step = shard_map(device_step, mesh, in_specs=(carry_specs, xs_specs),
-                     out_specs=(carry_specs, rows), check_rep=False)
+    step = jax.shard_map(device_step, mesh=mesh,
+                         in_specs=(carry_specs, xs_specs),
+                         out_specs=(carry_specs, rows), check_vma=False)
 
     w_all = np.zeros(s_pad, np.float32)
     w_all[:num_sites] = case_w / case_w.sum()
@@ -1266,9 +1266,9 @@ def execute_sharded(job, bundle, scheduler, codec, rounds: int,
         g = jax.lax.psum(jnp.einsum("s,sn->n", w, flat), "site")
         return engine.unflatten(g, layout)
 
-    global_mean = jax.jit(shard_map(_global_mean, mesh,
-                                    in_specs=(rows, rows), out_specs=rep,
-                                    check_rep=False))
+    global_mean = jax.jit(jax.shard_map(_global_mean, mesh=mesh,
+                                        in_specs=(rows, rows), out_specs=rep,
+                                        check_vma=False))
 
     def site_rows(site: int, r: int):
         ks = [bundle.sample(site, r * steps + k) for k in range(steps)]

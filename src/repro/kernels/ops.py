@@ -1,6 +1,6 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in ``interpret=True`` mode
+On a CPU backend the kernels execute in ``interpret=True`` mode
 (the kernel body runs under the Pallas interpreter — bit-faithful to the
 TPU program structure); on a real TPU pass ``interpret=False`` (the
 default flips on backend detection).
@@ -37,14 +37,14 @@ def rwkv6_scan(r, k, v, w, u, *, chunk: int = 128,
     return _rwkv6(r, k, v, w, u, chunk=chunk, interpret=interp)
 
 
-def fedagg(stacked_params, weights, *, block_n: int = 65536,
+def fedagg(stacked_params, weights, *, block_n: Optional[int] = None,
            interpret: Optional[bool] = None):
     """Streaming FedAvg aggregation over a [S, N] stacked param matrix."""
     interp = _default_interpret() if interpret is None else interpret
     return _fedagg(stacked_params, weights, block_n=block_n, interpret=interp)
 
 
-def trimmed_mean(stacked_params, active, f: int, *, block_n: int = 65536,
+def trimmed_mean(stacked_params, active, f: int, *, block_n: Optional[int] = None,
                  interpret: Optional[bool] = None):
     """Byzantine-robust coordinate-wise trimmed mean over the active
     rows of a [S, N] stacked param matrix (see kernels/robust.py)."""
@@ -54,7 +54,7 @@ def trimmed_mean(stacked_params, active, f: int, *, block_n: int = 65536,
                     interpret=interp)
 
 
-def masked_median(stacked_params, active, *, block_n: int = 65536,
+def masked_median(stacked_params, active, *, block_n: Optional[int] = None,
                   interpret: Optional[bool] = None):
     """Coordinate-wise median over the active rows of [S, N] — the
     trimmed mean at maximal trim depth."""
@@ -79,7 +79,7 @@ def fedagg_pytree(stacked_tree, weights, *, interpret: Optional[bool] = None):
     return eng.global_mean(stacked_tree, weights)
 
 
-def fedagg_dequant(q, scales, u, weights, *, block_c: int = 32,
+def fedagg_dequant(q, scales, u, weights, *, block_c: Optional[int] = None,
                    interpret: Optional[bool] = None):
     """Fused dequantize + Eq. 1 weighted fold over int8 site deltas
     ([S, C, chunk] values + [S, C] scales), also emitting the next
@@ -90,7 +90,7 @@ def fedagg_dequant(q, scales, u, weights, *, block_c: int = 32,
     return _fused(q, scales, u, weights, block_c=block_c, interpret=interp)
 
 
-def dequant_install(q, scales, base, *, block_c: int = 32,
+def dequant_install(q, scales, base, *, block_c: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Fused dequantize + install for quantized broadcast deltas
     ([S, C, chunk] int8 values + [S, C] scales + [S, C, chunk] held

@@ -9,7 +9,10 @@ values — so, like ``fedagg``, the kernel's job is to stream HBM once:
 
   grid = (ceil(C / block_c)); each cell loads a [block_c, chunk] slab
   into VMEM, reduces |x| along the chunk axis on the VPU for the scales,
-  and writes the int8 values and fp32 scales exactly once.
+  and writes the int8 values and fp32 scales exactly once.  Inside the
+  kernels the scales are a [C, 1] column (a 1-D block would not match
+  XLA's 1-D tiling); the public signatures keep them 1-D [C], so the
+  wire format (``QuantizedTensor``) is unchanged.
 
 ``chunk`` is the quantization granularity (one fp32 scale per chunk);
 keep it a multiple of 128 so compiled blocks tile the lane width
@@ -61,15 +64,16 @@ def quantize_dequantize_fp8_ref(mat):
 
 def _quantize_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)                    # [block_c, chunk]
-    scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1) / _QMAX, MIN_SCALE)
-    q = jnp.round(x / scale[:, None])                     # half-to-even, VPU
+    scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1, keepdims=True) / _QMAX,
+                        MIN_SCALE)                        # [block_c, 1]
+    q = jnp.round(x / scale)                              # half-to-even, VPU
     q_ref[...] = jnp.clip(q, -_QMAX, _QMAX).astype(jnp.int8)
     s_ref[...] = scale
 
 
 def _dequantize_kernel(q_ref, s_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)                    # [block_c, chunk]
-    o_ref[...] = q * s_ref[...][:, None]
+    o_ref[...] = q * s_ref[...]                           # scales [block_c, 1]
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -92,15 +96,15 @@ def quantize_int8(x, *, block_c: int = 256,
         in_specs=[pl.BlockSpec((block_c, chunk), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((block_c, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((block_c,), lambda i: (i,)),
+            pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((padded, chunk), jnp.int8),
-            jax.ShapeDtypeStruct((padded,), jnp.float32),
+            jax.ShapeDtypeStruct((padded, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
-    return (q[:c], s[:c]) if padded != c else (q, s)
+    return q[:c], s[:c, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -114,15 +118,16 @@ def dequantize_int8(q, s, *, block_c: int = 256,
         return jnp.zeros((0, chunk), jnp.float32)
     block_c = min(block_c, c)
     padded = _round_up(c, block_c)
+    s = s.reshape(c, 1)
     if padded != c:
         q = jnp.pad(q, ((0, padded - c), (0, 0)))
-        s = jnp.pad(s, ((0, padded - c),))
+        s = jnp.pad(s, ((0, padded - c), (0, 0)))
     out = pl.pallas_call(
         _dequantize_kernel,
         grid=(padded // block_c,),
         in_specs=[
             pl.BlockSpec((block_c, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((block_c,), lambda i: (i,)),
+            pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_c, chunk), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, chunk), jnp.float32),

@@ -4,11 +4,18 @@ Robust combine rules replace the Eq. 1 weighted mean when up to ``f`` of
 the stacked site rows may be adversarial (sign-flipped, rescaled, or
 noised uploads).  The coordinate-wise rules are rank statistics over the
 site axis, so the kernel's shape is the same streaming pass as
-``fedagg``: one [S, block_n] slab per grid cell, a per-coordinate sort
-over S (S is small — the site axis), and one [block_n] write.
+``fedagg``: one [S, block_n] slab per grid cell, a per-coordinate rank
+over S, and one [1, block_n] write.  The TPU kernel language has no
+sort, so ranks come from pairwise comparison: row i's rank is the count
+of rows j with x_j < x_i, plus the rows j < i with x_j == x_i (ties
+break by row index, like a stable sort).  The kept ranks are then
+folded in rank order, as a sort would leave them, so the result is
+bit-identical under any permutation of the rows.  That is O(S²) VPU work
+per coordinate — cheap next to the HBM stream, because S is the small
+site axis.
 
 Masked-row awareness: rows with ``active == 0`` (Algorithm-2 dropout,
-client sampling) are pushed to +inf before the sort, so they fall past
+client sampling) are pushed to +inf before ranking, so they fall past
 every active rank; the trim depth and the divisor use the *traced*
 active count, which is what lets the rule compile into the multi-round
 ``lax.scan`` where the active mask changes per round.
@@ -38,28 +45,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_LANE = 128   # TPU lane width — pad so compiled blocks tile cleanly
+from repro.kernels.fedagg import _round_up, site_block_n
 
-
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
+# [S, block_n]-sized temporaries the rank body keeps live (masked
+# values, ranks, one comparison, one rank select), for the VMEM fit
+_TRIM_TEMPS = 4
 
 
 def _trim_block(x, a, f: int):
     """Coordinate-wise trimmed mean of the active rows of one block.
 
-    x: [S, n] values; a: [S] active mask (float, >0.5 = active); f: trim
-    depth.  Inactive rows sort to +inf (past every active rank); the
-    where-before-sum keeps ``inf · 0`` out of the fold.
+    x: [S, n] values; a: [S, 1] active mask (float, >0.5 = active); f:
+    trim depth.  Returns [1, n].  Inactive rows rank past every active
+    row (+inf, ties by index); the where-before-sum keeps ``inf · 0``
+    out of the fold.
     """
     x = x.astype(jnp.float32)
-    act = a > 0.5
+    act = a > 0.5                                         # [S, 1]
     k = jnp.sum(act.astype(jnp.int32))
-    xs = jnp.sort(jnp.where(act[:, None], x, jnp.inf), axis=0)
-    r = jax.lax.broadcasted_iota(jnp.int32, xs.shape, 0)
+    xm = jnp.where(act, x, jnp.inf)
+    row = jax.lax.broadcasted_iota(jnp.int32, xm.shape, 0)
+    rank = jnp.zeros(xm.shape, jnp.int32)
+    for j in range(xm.shape[0]):                          # static, S small
+        xj = xm[j:j + 1, :]
+        rank += ((xj < xm) | ((xj == xm) & (row > j))).astype(jnp.int32)
     fe = jnp.minimum(jnp.int32(f), jnp.maximum(k - 1, 0) // 2)
-    keep = (r >= fe) & (r < k - fe)
-    total = jnp.sum(jnp.where(keep, xs, 0.0), axis=0)
+    # fold the kept ranks in rank order, so the sum (and so the result)
+    # is bit-identical under any permutation of the site rows
+    total = jnp.zeros((1, xm.shape[1]), jnp.float32)
+    for r in range(xm.shape[0]):
+        v = jnp.sum(jnp.where(rank == r, xm, 0.0), axis=0, keepdims=True)
+        total = total + jnp.where((r >= fe) & (r < k - fe), v, 0.0)
     return total / jnp.maximum(k - 2 * fe, 1).astype(jnp.float32)
 
 
@@ -68,7 +84,7 @@ def _trimmed_kernel(f, x_ref, a_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("f", "block_n", "interpret"))
-def trimmed_mean(stacked, active, f: int, *, block_n: int = 65536,
+def trimmed_mean(stacked, active, f: int, *, block_n: Optional[int] = None,
                  interpret: Optional[bool] = None):
     """Coordinate-wise trimmed mean over the active rows of [S, N].
 
@@ -78,8 +94,8 @@ def trimmed_mean(stacked, active, f: int, *, block_n: int = 65536,
     if interpret is None:
         interpret = jax.default_backend() not in ("tpu", "gpu")
     s, n = stacked.shape
-    active = jnp.asarray(active, jnp.float32)
-    block_n = min(block_n, _round_up(n, _LANE))
+    active = jnp.asarray(active, jnp.float32).reshape(s, 1)
+    block_n = site_block_n(s, n, block_n, temps=_TRIM_TEMPS)
     padded = _round_up(n, block_n)
     if padded != n:
         stacked = jnp.pad(stacked, ((0, 0), (0, padded - n)))
@@ -88,16 +104,16 @@ def trimmed_mean(stacked, active, f: int, *, block_n: int = 65536,
         grid=(padded // block_n,),
         in_specs=[
             pl.BlockSpec((s, block_n), lambda i: (0, i)),
-            pl.BlockSpec((s,), lambda i: (0,)),
+            pl.BlockSpec((s, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((padded,), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, padded), jnp.float32),
         interpret=interpret,
     )(stacked, active)
-    return out[:n] if padded != n else out
+    return out[0, :n]
 
 
-def masked_median(stacked, active, *, block_n: int = 65536,
+def masked_median(stacked, active, *, block_n: Optional[int] = None,
                   interpret: Optional[bool] = None):
     """Coordinate-wise median over the active rows of [S, N] — the
     trimmed mean at maximal trim depth (f = S clamps to ⌊(k−1)/2⌋)."""
@@ -110,8 +126,9 @@ def trimmed_mean_ref(stacked, active, f: int):
     """jnp twin of :func:`trimmed_mean` — the identical op sequence on
     the whole [S, N] buffer (the CPU engine path; bit-exact vs the
     kernel because both run :func:`_trim_block` elementwise over N)."""
-    return _trim_block(jnp.asarray(stacked),
-                       jnp.asarray(active, jnp.float32), f)
+    stacked = jnp.asarray(stacked)
+    act = jnp.asarray(active, jnp.float32).reshape(stacked.shape[0], 1)
+    return _trim_block(stacked, act, f)[0]
 
 
 def masked_median_ref(stacked, active):

@@ -1,8 +1,10 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) combo.
 
-The two lines above MUST stay first: jax locks the device count on first
+The lines above MUST stay first (they add to the caller's XLA_FLAGS,
+never replace them): jax locks the device count on first
 initialization, and the production meshes need 512 placeholder devices.
 
 For each combination this script:

@@ -1,5 +1,6 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
 """§Perf hillclimb driver: lower+compile named variants of the three chosen
 (arch × shape) pairs and record the roofline deltas.
 

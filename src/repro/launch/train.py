@@ -278,4 +278,6 @@ def make_parser():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(make_parser().parse_args())

@@ -1,6 +1,5 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
-import os
-
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode;
+the compiled TPU lowering is covered by tests/test_tpu_compile.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,13 +9,6 @@ from repro.kernels import ops
 from repro.kernels.ref import fedagg_ref, flash_attention_ref, rwkv6_scan_ref
 
 KEY = jax.random.PRNGKey(7)
-
-# The federation kernels normally run under the auto-selected interpret
-# mode (the Pallas interpreter on this CPU container).  Setting
-# REPRO_PALLAS_COMPILED=1 additionally sweeps the compiled
-# interpret=False lowering — opt-in, for hardware that can lower it.
-INTERPRET_MODES = [None] + ([False] if os.environ.get(
-    "REPRO_PALLAS_COMPILED") == "1" else [])
 
 
 @pytest.mark.parametrize("b,hq,hkv,l,d", [
@@ -88,11 +80,10 @@ def test_rwkv6_scan_bf16():
 @pytest.mark.parametrize("s,n,block", [(4, 1024, 256), (8, 4096, 4096),
                                        (16, 512, 512), (2, 65536, 65536)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("interpret", INTERPRET_MODES)
-def test_fedagg_sweep(s, n, block, dtype, interpret):
+def test_fedagg_sweep(s, n, block, dtype):
     x = jax.random.normal(KEY, (s, n), dtype)
     w = jax.nn.softmax(jax.random.normal(KEY, (s,)))
-    out = ops.fedagg(x, w, block_n=block, interpret=interpret)
+    out = ops.fedagg(x, w, block_n=block)
     ref = fedagg_ref(x, w)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -101,8 +92,7 @@ def test_fedagg_sweep(s, n, block, dtype, interpret):
 
 @pytest.mark.parametrize("s,c,chunk,block_c", [(3, 7, 256, 4), (4, 16, 128, 16),
                                                (2, 1, 128, 32)])
-@pytest.mark.parametrize("interpret", INTERPRET_MODES)
-def test_fedagg_dequant_fuses_decode_and_fold(s, c, chunk, block_c, interpret):
+def test_fedagg_dequant_fuses_decode_and_fold(s, c, chunk, block_c):
     """The compressed round engine's one-pass server step: dequantize +
     Eq. 1 fold + error-feedback residual, vs the separate numpy codec."""
     from repro.comms.compression import MIN_SCALE
@@ -115,23 +105,21 @@ def test_fedagg_dequant_fuses_decode_and_fold(s, c, chunk, block_c, interpret):
     deq = q.astype(np.float32) * scale[..., None]
     g, r = ops.fedagg_dequant(jnp.asarray(q), jnp.asarray(scale),
                               jnp.asarray(u), jnp.asarray(w),
-                              block_c=block_c, interpret=interpret)
+                              block_c=block_c)
     np.testing.assert_allclose(np.asarray(g), np.einsum("s,sct->ct", w, deq),
                                rtol=1e-6, atol=1e-7)
     np.testing.assert_allclose(np.asarray(r), u - deq, rtol=1e-5, atol=1e-7)
 
 
-@pytest.mark.parametrize("interpret", INTERPRET_MODES)
-def test_fedagg_dequant_matches_jnp_quantize_path(interpret):
+def test_fedagg_dequant_matches_jnp_quantize_path():
     """Kernel quantize → fused fold agrees with the traced jnp twin the
     CPU engine path uses (quantize_dequantize_ref + einsum fold)."""
     from repro.kernels.quantize import quantize_dequantize_ref
     rng = np.random.default_rng(8)
     u = jnp.asarray((rng.normal(size=(4, 5, 128)) * 0.02).astype(np.float32))
     w = jnp.asarray(rng.dirichlet(np.ones(4)).astype(np.float32))
-    q, sc = ops.quantize_int8(u.reshape(20, 128), interpret=interpret)
-    g, _ = ops.fedagg_dequant(q.reshape(4, 5, 128), sc.reshape(4, 5), u, w,
-                              interpret=interpret)
+    q, sc = ops.quantize_int8(u.reshape(20, 128))
+    g, _ = ops.fedagg_dequant(q.reshape(4, 5, 128), sc.reshape(4, 5), u, w)
     deq = quantize_dequantize_ref(u)
     np.testing.assert_allclose(np.asarray(g),
                                np.einsum("s,sct->ct", np.asarray(w),
@@ -157,22 +145,20 @@ def test_fedagg_pytree_matches_eq1():
     (4, 1024, 1, 256), (8, 4096, 2, 4096), (5, 512, 2, 512),
     (16, 300, 5, 512), (3, 65536, 1, 65536),
 ])
-@pytest.mark.parametrize("interpret", INTERPRET_MODES)
-def test_trimmed_mean_kernel_matches_ref(s, n, f, block, interpret):
+def test_trimmed_mean_kernel_matches_ref(s, n, f, block):
     """Pallas coordinate-wise trimmed mean == the jnp twin, bit-exact
     (same op sequence per block), with every row active."""
     from repro.kernels.robust import trimmed_mean_ref
     x = jax.random.normal(KEY, (s, n), jnp.float32) * 3.0
     active = jnp.ones((s,), bool)
-    out = ops.trimmed_mean(x, active, f, block_n=block, interpret=interpret)
+    out = ops.trimmed_mean(x, active, f, block_n=block)
     ref = trimmed_mean_ref(x, active, f)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 @pytest.mark.parametrize("s,n,block", [(4, 1024, 256), (7, 2048, 2048),
                                        (9, 513, 1024)])
-@pytest.mark.parametrize("interpret", INTERPRET_MODES)
-def test_masked_median_kernel_matches_ref_and_numpy(s, n, block, interpret):
+def test_masked_median_kernel_matches_ref_and_numpy(s, n, block):
     """Pallas masked median == jnp twin bit-exact, and == np.median on
     the active rows (the trim-at-max-depth construction is a real
     median for odd AND even active counts)."""
@@ -182,25 +168,23 @@ def test_masked_median_kernel_matches_ref_and_numpy(s, n, block, interpret):
     mask[:: max(s // 2, 1)] = True          # keep all, then drop one row
     mask[s - 1] = False
     active = jnp.asarray(mask)
-    out = ops.masked_median(x, active, block_n=block, interpret=interpret)
+    out = ops.masked_median(x, active, block_n=block)
     ref = masked_median_ref(x, active)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     np_med = np.median(np.asarray(x)[mask], axis=0)
     np.testing.assert_allclose(np.asarray(out), np_med, rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("interpret", INTERPRET_MODES)
-def test_trimmed_mean_kernel_masked_rows(interpret):
+def test_trimmed_mean_kernel_masked_rows():
     """Inactive rows are invisible: trimming over a masked [S, N] buffer
     equals trimming the compacted active-only buffer."""
     from repro.kernels.robust import trimmed_mean_ref
     s, n, f = 8, 768, 1
     x = jax.random.normal(KEY, (s, n), jnp.float32)
     mask = np.array([1, 0, 1, 1, 0, 1, 1, 1], bool)
-    out = ops.trimmed_mean(x, jnp.asarray(mask), f, interpret=interpret)
+    out = ops.trimmed_mean(x, jnp.asarray(mask), f)
     compact = ops.trimmed_mean(jnp.asarray(np.asarray(x)[mask]),
-                               jnp.ones(int(mask.sum()), bool), f,
-                               interpret=interpret)
+                               jnp.ones(int(mask.sum()), bool), f)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(compact))
     ref = trimmed_mean_ref(x, jnp.asarray(mask), f)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))

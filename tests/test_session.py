@@ -160,6 +160,15 @@ def test_tcp_process_transport_parity():
     _assert_trees_close(stacked.global_params, tcp.global_params)
 
 
+def test_tcp_transport_refuses_on_a_tpu_host(monkeypatch):
+    """A TPU chip belongs to one process: with the chip held here, the
+    tcp transport fails fast naming that rule instead of spawning site
+    processes that would wait on the chip or land on the CPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        _token_job(transport="tcp").run()
+
+
 def test_socket_transport_rejects_pooled():
     with pytest.raises(ValueError, match="pooled"):
         _token_job(strategy="pooled", transport="thread").run()
