@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.comms.compression import (KEEP_GLOBALS_DEFAULT, Codec,
                                      DownlinkCompressor, UploadCompressor,
                                      decode_download, decode_upload,
@@ -581,7 +582,9 @@ class FederatedJob:
         them share.  With nothing on disk the run starts fresh
         (``result.resumed_from`` is None).  At checkpoint-aligned
         boundaries the resumed loss trajectory is identical to an
-        uninterrupted run."""
+        uninterrupted run.  The run's host spans are read back with
+        ``repro.telemetry.spans()``."""
+        telemetry.start_run()
         return resolve_transport(self.transport).execute(
             self, self.rounds if rounds is None else rounds, resume=resume)
 

@@ -456,14 +456,15 @@ class AggregationEngine:
         scale = round_inputs.get("weight_scale")
         spec = parse_aggregator(getattr(ctx, "aggregator", None))
         topo = ctx.topology
-        if topo.is_pods:
-            s = jax.tree.leaves(params_stacked)[0].shape[0]
-            return self.aggregate_pods(
-                params_stacked, ctx.case_weights, topo.pod_of(s),
-                topo.num_pods, active, topo.intra, topo.inter, scale=scale,
-                aggregator=spec)
-        return self.aggregate(params_stacked, ctx.case_weights, active,
-                              scale=scale, aggregator=spec)
+        with jax.named_scope("fold"):
+            if topo.is_pods:
+                s = jax.tree.leaves(params_stacked)[0].shape[0]
+                return self.aggregate_pods(
+                    params_stacked, ctx.case_weights, topo.pod_of(s),
+                    topo.num_pods, active, topo.intra, topo.inter,
+                    scale=scale, aggregator=spec)
+            return self.aggregate(params_stacked, ctx.case_weights, active,
+                                  scale=scale, aggregator=spec)
 
 
 _DEFAULT_ENGINE: Optional[AggregationEngine] = None

@@ -148,8 +148,10 @@ def build_fl_round(ctx: FLContext, remat_local: bool = False):
 
     def site_train_step(params, opt, batch, strat_ref, noise_key=None):
         def lf(p, b):
-            loss, metrics = ctx.loss_fn(p, b)
-            loss = loss + strategy.local_loss_extra(p, strat_ref, ctx)
+            # backward ops read transpose(jvp(forward)) in the trace
+            with jax.named_scope("forward"):
+                loss, metrics = ctx.loss_fn(p, b)
+                loss = loss + strategy.local_loss_extra(p, strat_ref, ctx)
             return loss, metrics
 
         if dp is not None:
@@ -159,8 +161,9 @@ def build_fl_round(ctx: FLContext, remat_local: bool = False):
             # accountant's calibration
             grads, loss, metrics, gnorm = dp_gradients(
                 lf, params, batch, noise_key, dp)
-            updates, opt = ctx.optimizer.update(grads, opt, params)
-            params = apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt = ctx.optimizer.update(grads, opt, params)
+                params = apply_updates(params, updates)
             return params, opt, {"loss": loss, "grad_norm": gnorm,
                                  **metrics}
 
@@ -187,12 +190,13 @@ def build_fl_round(ctx: FLContext, remat_local: bool = False):
         else:
             (loss, metrics), grads = jax.value_and_grad(
                 lf, has_aux=True)(params, batch)
-        if ctx.grad_clip:
-            grads, gnorm = clip_by_global_norm(grads, ctx.grad_clip)
-        else:
-            gnorm = jnp.zeros(())
-        updates, opt = ctx.optimizer.update(grads, opt, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            if ctx.grad_clip:
+                grads, gnorm = clip_by_global_norm(grads, ctx.grad_clip)
+            else:
+                gnorm = jnp.zeros(())
+            updates, opt = ctx.optimizer.update(grads, opt, params)
+            params = apply_updates(params, updates)
         return params, opt, {"loss": loss, "grad_norm": gnorm, **metrics}
 
     if remat_local:
@@ -262,7 +266,8 @@ def build_fl_round(ctx: FLContext, remat_local: bool = False):
         if adv is not None and adv.flips_labels:
             batches = adv.perturb_batches(batches, adv_mask)
         fl_state = strategy.pre_exchange(fl_state, ri, ctx)
-        fl_state, metrics = local_phase(fl_state, batches, active)
+        with jax.named_scope("site_update"):
+            fl_state, metrics = local_phase(fl_state, batches, active)
         if adv is not None and adv.flips_params:
             # perturb what malicious ACTIVE sites expose to aggregation
             # (between local training and the exchange — the same seam

@@ -42,9 +42,15 @@ buffers) **donated**, per-round losses/metrics accumulated into a
     references are all device state inside the scan.
 
 Chunk boundaries align with checkpoint rounds (the only places a global
-model must materialize); compile time is measured once per chunk shape
-via AOT lowering and reported as ``JobResult.compile_s``, separate from
-the per-round ``step_s``.
+model must materialize).  Each chunk runs under ``repro.telemetry`` spans:
+``repro.chunk`` around the host iteration (``RoundRecorder.chunk``), with
+``repro.chunk.inputs`` (its ``bytes`` put on the device),
+``repro.chunk.execute`` (dispatch to ``block_until_ready``) and
+``repro.chunk.record`` inside, and ``repro.chunk.lower`` /
+``repro.chunk.compile`` once per chunk shape.  ``JobResult.compile_s`` is
+the sum of the lower and compile spans; a round's ``step_s`` is its share
+of the execute span and its ``wall_s`` its share of the ``repro.chunk``
+span less the lowering and compiling inside it.
 
 The host path is still taken for: the ``topk-sparse`` codec (data-
 dependent index payloads — the fixed-k ``topk-fixed`` variant
@@ -56,13 +62,13 @@ transports are untouched.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.comms.compression import KEEP_GLOBALS_DEFAULT
 from repro.core import federation as F
 from repro.core import stacking
@@ -110,12 +116,12 @@ class _ChunkRunner:
     """Compile-once-per-chunk-shape executor with donated carry buffers.
 
     ``fn(carry, xs) -> (carry, ys)`` is AOT-lowered and compiled the
-    first time each chunk length appears — compile time is measured
-    exactly once per program shape and reported separately
-    (``JobResult.compile_s``) instead of polluting round 0's ``step_s``.
-    The carry (fl_state + engine buffers) is donated, so K rounds run
-    without an extra resident copy of the federation's parameters; the
-    caller must never touch a carry it has already passed in.
+    first time each chunk length appears, under the ``repro.chunk.lower``
+    and ``repro.chunk.compile`` spans, whose sum is ``compile_s``; round
+    0's ``step_s`` never absorbs it.  The carry (fl_state + engine
+    buffers) is donated, so K rounds run without an extra resident copy
+    of the federation's parameters; the caller must never touch a carry
+    it has already passed in.
     """
 
     def __init__(self, fn: Callable):
@@ -127,15 +133,20 @@ class _ChunkRunner:
         """Execute one chunk; returns ``(carry', ys, exec_seconds)``."""
         compiled = self._cache.get(kc)
         if compiled is None:
-            t0 = time.perf_counter()
-            compiled = (jax.jit(self.fn, donate_argnums=0)
-                        .lower(carry, xs).compile())
+            with telemetry.span("repro.chunk.lower", rounds=kc) as lower:
+                lowered = jax.jit(self.fn, donate_argnums=0).lower(carry, xs)
+            with telemetry.span("repro.chunk.compile", rounds=kc) as comp:
+                compiled = lowered.compile()
             self._cache[kc] = compiled
-            self.compile_s += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        carry, ys = compiled(carry, xs)
-        jax.block_until_ready((carry, ys))
-        return carry, ys, time.perf_counter() - t0
+            self.compile_s += lower.seconds + comp.seconds
+        with telemetry.span("repro.chunk.execute") as ex:
+            carry, ys = compiled(carry, xs)
+            jax.block_until_ready((carry, ys))
+        return carry, ys, ex.seconds
+
+
+def _nbytes(tree) -> int:
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
 def _pairings(masks: np.ndarray, seed: int):
@@ -300,17 +311,19 @@ def _compressed_fold(u, w, codec_name: str, chunkw: int, align: int,
         for x in leaves:
             mat, n = _to_chunks(x, chunkw, align)
             s, rows, c = mat.shape
-            q, sc = ops.quantize_int8(mat.reshape(s * rows, c))
+            with jax.named_scope("quantize"):
+                q, sc = ops.quantize_int8(mat.reshape(s * rows, c))
             g, res = ops.fedagg_dequant(q.reshape(s, rows, c),
                                         sc.reshape(s, rows), mat, w)
             g_leaves.append(_from_chunks(g[None], x.shape[1:], n)[0])
             r_leaves.append(_from_chunks(res, x.shape[1:], n))
         return (jax.tree.unflatten(treedef, g_leaves),
                 jax.tree.unflatten(treedef, r_leaves))
-    if codec_name == "topk-fixed":
-        deq = _topk_tree(u, fraction)
-    else:
-        deq = _qdq_tree(u, chunkw, align, codec_name)
+    with jax.named_scope("quantize"):
+        if codec_name == "topk-fixed":
+            deq = _topk_tree(u, fraction)
+        else:
+            deq = _qdq_tree(u, chunkw, align, codec_name)
     if dense is not None:
         # `dense` is either a per-round scalar (the top-k sparsifier's
         # round-0 bootstrap) or a per-site [S] mask (bidirectional
@@ -359,17 +372,21 @@ def _down_install_tree(gref, down_ref, codec_name: str, chunkw: int,
         def one(d, h):
             mat, n = _to_chunks(d, chunkw, align)
             s, rows, c = mat.shape
-            q, sc = ops.quantize_int8(mat.reshape(s * rows, c))
+            with jax.named_scope("quantize"):
+                q, sc = ops.quantize_int8(mat.reshape(s * rows, c))
             hmat, _ = _to_chunks(h, chunkw, align)
-            inst = ops.dequant_install(q.reshape(s, rows, c),
-                                       sc.reshape(s, rows), hmat)
+            with jax.named_scope("dequant_install"):
+                inst = ops.dequant_install(q.reshape(s, rows, c),
+                                           sc.reshape(s, rows), hmat)
             return _from_chunks(inst, d.shape[1:], n)
         return jax.tree.map(one, delta, down_ref)
-    if codec_name == "topk-fixed":
-        qd = _topk_tree(delta, fraction)
-    else:
-        qd = _qdq_tree(delta, chunkw, align, codec_name)
-    return jax.tree.map(jnp.add, down_ref, qd)
+    with jax.named_scope("quantize"):
+        if codec_name == "topk-fixed":
+            qd = _topk_tree(delta, fraction)
+        else:
+            qd = _qdq_tree(delta, chunkw, align, codec_name)
+    with jax.named_scope("dequant_install"):
+        return jax.tree.map(jnp.add, down_ref, qd)
 
 
 def _bootstrap_masks(masks: np.ndarray, keep: int) -> np.ndarray:
@@ -486,42 +503,45 @@ def _run_sync_scan(job, bundle, scheduler, rounds: int,
                       job.ckpt_every if recorder.store else None,
                       start=start_round)
     for kc in plan:
-        if device_data:
-            xs = jnp.arange(r0, r0 + kc)
-        else:
-            xs = {"batches": _chunk_batches(bundle, r0, kc, job.local_steps,
-                                            pooled),
-                  "active": jnp.asarray(masks[r0:r0 + kc]),
-                  "partner": jnp.asarray(partner[r0:r0 + kc]),
-                  "is_receiver": jnp.asarray(is_recv[r0:r0 + kc])}
-            if wscale is not None:
-                xs["wscale"] = jnp.asarray(wscale[r0:r0 + kc])
-        carry, ys, exec_s = runner.run(kc, carry, xs)
-        state = carry[0] if device_data else carry
-        losses = np.asarray(ys["loss"])
-        if device_data:
-            rows = np.asarray(ys["active"])
-            p_rows, r_rows = np.asarray(ys["partner"]), np.asarray(
-                ys["is_receiver"])
-            masks_seen.append(rows)
-        else:
-            rows = masks[r0:r0 + kc]
-            p_rows, r_rows = partner[r0:r0 + kc], is_recv[r0:r0 + kc]
-        step_s = exec_s / kc
-        for i in range(kc):
-            extra = {"step_s": step_s, "wall_s": step_s}
-            if needs_pair:
-                extra["partner"] = [int(v) for v in p_rows[i]]
-                extra["is_receiver"] = [bool(v) for v in r_rows[i]]
-            recorder.record(
-                r0 + i, losses[i], rows[i],
-                global_fn=(lambda st=state: F.global_model(st, ctx))
-                if i == kc - 1 else None,
-                extra=extra)
-        recorder.save_state(r0 + kc - 1,
-                            lambda: jax.tree.map(np.asarray, carry),
-                            meta={"engine": "sync-scan",
-                                  "dp": job.dp_tag()})
+        with recorder.chunk(r0, kc):
+            with telemetry.span("repro.chunk.inputs") as inputs:
+                if device_data:
+                    xs = jnp.arange(r0, r0 + kc)
+                else:
+                    xs = {"batches": _chunk_batches(bundle, r0, kc,
+                                                    job.local_steps, pooled),
+                          "active": jnp.asarray(masks[r0:r0 + kc]),
+                          "partner": jnp.asarray(partner[r0:r0 + kc]),
+                          "is_receiver": jnp.asarray(is_recv[r0:r0 + kc])}
+                    if wscale is not None:
+                        xs["wscale"] = jnp.asarray(wscale[r0:r0 + kc])
+                inputs.set(bytes=_nbytes(xs))
+            carry, ys, exec_s = runner.run(kc, carry, xs)
+            state = carry[0] if device_data else carry
+            with telemetry.span("repro.chunk.record"):
+                losses = np.asarray(ys["loss"])
+                if device_data:
+                    rows = np.asarray(ys["active"])
+                    p_rows, r_rows = np.asarray(ys["partner"]), np.asarray(
+                        ys["is_receiver"])
+                    masks_seen.append(rows)
+                else:
+                    rows = masks[r0:r0 + kc]
+                    p_rows, r_rows = partner[r0:r0 + kc], is_recv[r0:r0 + kc]
+                for i in range(kc):
+                    extra = {"step_s": exec_s / kc}
+                    if needs_pair:
+                        extra["partner"] = [int(v) for v in p_rows[i]]
+                        extra["is_receiver"] = [bool(v) for v in r_rows[i]]
+                    recorder.record(
+                        r0 + i, losses[i], rows[i],
+                        global_fn=(lambda st=state: F.global_model(st, ctx))
+                        if i == kc - 1 else None,
+                        extra=extra)
+                recorder.save_state(r0 + kc - 1,
+                                    lambda: jax.tree.map(np.asarray, carry),
+                                    meta={"engine": "sync-scan",
+                                          "dp": job.dp_tag()})
         r0 += kc
     all_masks = (np.concatenate(masks_seen) if masks_seen
                  else masks[start_round:])
@@ -640,10 +660,12 @@ def _run_compressed_scan(job, bundle, scheduler, rounds: int, codec,
             if pod_ids is not None:
                 def fold_tree(deq, active=active, scale=scale):
                     return fold_plain(deq, None, active, scale)
-            gdelta, new_res = _compressed_fold(
-                u, w, codec.name, chunkw, align, accel, engine,
-                fold_tree=fold_tree,
-                dense=x["bootstrap"] if topk else None, fraction=fraction)
+            with jax.named_scope("fold"):
+                gdelta, new_res = _compressed_fold(
+                    u, w, codec.name, chunkw, align, accel, engine,
+                    fold_tree=fold_tree,
+                    dense=x["bootstrap"] if topk else None,
+                    fraction=fraction)
             if error_feedback:
                 res = stacking.where_site(active, new_res, res)
             ref = jax.tree.map(jnp.add, ref, gdelta)
@@ -685,21 +707,24 @@ def _run_compressed_scan(job, bundle, scheduler, rounds: int, codec,
                 if pod_ids is not None:
                     def fold_tree(deq, active=active, scale=scale):
                         return fold_plain(deq, None, active, scale)
-                gdelta, new_res = _compressed_fold(
-                    u, w, codec.name, chunkw, align, accel, engine,
-                    fold_tree=fold_tree, dense=boot if topk else None,
-                    fraction=fraction)
+                with jax.named_scope("fold"):
+                    gdelta, new_res = _compressed_fold(
+                        u, w, codec.name, chunkw, align, accel, engine,
+                        fold_tree=fold_tree, dense=boot if topk else None,
+                        fraction=fraction)
                 if error_feedback:
                     res = stacking.where_site(active, new_res, res)
                 # per-site decode: g = Σ wₛ(anchorₛ + deQ(uₛ)) — the
                 # anchors differ per site, so the fold carries them too
-                gref = jax.tree.map(
-                    jnp.add, fold_plain(anchor, w, active, scale), gdelta)
+                with jax.named_scope("fold"):
+                    gref = jax.tree.map(
+                        jnp.add, fold_plain(anchor, w, active, scale), gdelta)
             else:
                 # down-only compression: uploads ride dense fp32
-                gref = fold_plain(
-                    jax.tree.map(lambda p: p.astype(jnp.float32),
-                                 st["params"]), w, active, scale)
+                with jax.named_scope("fold"):
+                    gref = fold_plain(
+                        jax.tree.map(lambda p: p.astype(jnp.float32),
+                                     st["params"]), w, active, scale)
             # downlink: quantized delta against each site's held
             # reference; bootstrap rows (new/evicted) get the dense global
             inst = _down_install_tree(gref, dref, down_codec.name, d_chunkw,
@@ -767,32 +792,37 @@ def _run_compressed_scan(job, bundle, scheduler, rounds: int, codec,
     for kc in chunk_plan(rounds, job.chunk_rounds,
                          job.ckpt_every if recorder.store else None,
                          start=start_round):
-        xs = {"batches": _chunk_batches(bundle, r0, kc, job.local_steps,
-                                        False),
-              "active": jnp.asarray(masks[r0:r0 + kc])}
-        if wscale is not None:
-            xs["wscale"] = jnp.asarray(wscale[r0:r0 + kc])
-        if down:
-            xs["bootstrap"] = jnp.asarray(boot_mask[r0:r0 + kc])
-        elif topk:
-            xs["bootstrap"] = jnp.asarray(
-                [r == 0 for r in range(r0, r0 + kc)])
-        carry, ys, exec_s = runner.run(kc, carry, xs)
-        losses = np.asarray(ys["loss"])
-        step_s = exec_s / kc
-        for i in range(kc):
-            extra = {"step_s": step_s, "wall_s": step_s,
-                     "upload_bytes": int(round_up_bytes[r0 + i])}
-            if down:
-                extra["download_bytes"] = int(round_down_bytes[r0 + i])
-            recorder.record(
-                r0 + i, losses[i], masks[r0 + i],
-                global_fn=(lambda c=carry: c[1]) if i == kc - 1 else None,
-                extra=extra)
-        recorder.save_state(r0 + kc - 1,
-                            lambda: jax.tree.map(np.asarray, carry),
-                            meta={"engine": engine_tag,
-                                  "dp": job.dp_tag()})
+        with recorder.chunk(r0, kc):
+            with telemetry.span("repro.chunk.inputs") as inputs:
+                xs = {"batches": _chunk_batches(bundle, r0, kc,
+                                                job.local_steps, False),
+                      "active": jnp.asarray(masks[r0:r0 + kc])}
+                if wscale is not None:
+                    xs["wscale"] = jnp.asarray(wscale[r0:r0 + kc])
+                if down:
+                    xs["bootstrap"] = jnp.asarray(boot_mask[r0:r0 + kc])
+                elif topk:
+                    xs["bootstrap"] = jnp.asarray(
+                        [r == 0 for r in range(r0, r0 + kc)])
+                inputs.set(bytes=_nbytes(xs))
+            carry, ys, exec_s = runner.run(kc, carry, xs)
+            with telemetry.span("repro.chunk.record"):
+                losses = np.asarray(ys["loss"])
+                for i in range(kc):
+                    extra = {"step_s": exec_s / kc,
+                             "upload_bytes": int(round_up_bytes[r0 + i])}
+                    if down:
+                        extra["download_bytes"] = int(
+                            round_down_bytes[r0 + i])
+                    recorder.record(
+                        r0 + i, losses[i], masks[r0 + i],
+                        global_fn=(lambda c=carry: c[1]) if i == kc - 1
+                        else None,
+                        extra=extra)
+                recorder.save_state(r0 + kc - 1,
+                                    lambda: jax.tree.map(np.asarray, carry),
+                                    meta={"engine": engine_tag,
+                                          "dp": job.dp_tag()})
         r0 += kc
     state, reference = carry[0], carry[1]
     uploads = int(masks[start_round:].sum())
@@ -934,8 +964,10 @@ def _run_buffered_scan(job, bundle, scheduler, rounds: int, codec,
                   c.get("ring", jnp.zeros((), jnp.float32)),
                   c.get("residual", jnp.zeros((), jnp.float32)),
                   jnp.zeros((num_sites,), bool), jnp.zeros((), jnp.int32))
-            (pflat, acc, accw, count, version, base, gflat, ring, residual,
-             uploaded, folds) = jax.lax.fori_loop(0, num_sites, arrival, a0)
+            with jax.named_scope("fold"):
+                (pflat, acc, accw, count, version, base, gflat, ring,
+                 residual, uploaded, folds) = jax.lax.fori_loop(
+                    0, num_sites, arrival, a0)
             # uploaders pull the latest global and re-anchor
             pflat = jnp.where(uploaded[:, None], gflat[None, :], pflat)
             base = jnp.where(uploaded, version, base)
@@ -964,28 +996,30 @@ def _run_buffered_scan(job, bundle, scheduler, rounds: int, codec,
     for kc in chunk_plan(rounds, job.chunk_rounds,
                          job.ckpt_every if recorder.store else None,
                          start=start_round):
-        xs = {"batches": _chunk_batches(bundle, r0, kc, job.local_steps,
-                                        False),
-              "active": jnp.asarray(masks[r0:r0 + kc]),
-              "order": jnp.asarray(order[r0:r0 + kc]),
-              "n_act": jnp.asarray(n_act[r0:r0 + kc])}
-        carry, ys, exec_s = runner.run(kc, carry, xs)
-        losses = np.asarray(ys["loss"])
-        versions = np.asarray(ys["version"])
-        total_folds += int(np.asarray(ys["folds"]).sum())
-        step_s = exec_s / kc
-        for i in range(kc):
-            recorder.record(
-                r0 + i, losses[i], masks[r0 + i],
-                global_fn=(lambda c=carry: engine.unflatten(c["gflat"],
-                                                            layout))
-                if i == kc - 1 else None,
-                extra={"version": int(versions[i]), "step_s": step_s,
-                       "wall_s": step_s})
-        recorder.save_state(r0 + kc - 1,
-                            lambda: jax.tree.map(np.asarray, carry),
-                            meta={"engine": "buffered-scan",
-                                  "dp": job.dp_tag()})
+        with recorder.chunk(r0, kc):
+            with telemetry.span("repro.chunk.inputs") as inputs:
+                xs = {"batches": _chunk_batches(bundle, r0, kc,
+                                                job.local_steps, False),
+                      "active": jnp.asarray(masks[r0:r0 + kc]),
+                      "order": jnp.asarray(order[r0:r0 + kc]),
+                      "n_act": jnp.asarray(n_act[r0:r0 + kc])}
+                inputs.set(bytes=_nbytes(xs))
+            carry, ys, exec_s = runner.run(kc, carry, xs)
+            with telemetry.span("repro.chunk.record"):
+                losses = np.asarray(ys["loss"])
+                versions = np.asarray(ys["version"])
+                total_folds += int(np.asarray(ys["folds"]).sum())
+                for i in range(kc):
+                    recorder.record(
+                        r0 + i, losses[i], masks[r0 + i],
+                        global_fn=(lambda c=carry: engine.unflatten(
+                            c["gflat"], layout)) if i == kc - 1 else None,
+                        extra={"version": int(versions[i]),
+                               "step_s": exec_s / kc})
+                recorder.save_state(r0 + kc - 1,
+                                    lambda: jax.tree.map(np.asarray, carry),
+                                    meta={"engine": "buffered-scan",
+                                          "dp": job.dp_tag()})
         r0 += kc
     state = carry["state"]
     global_params = engine.unflatten(carry["gflat"], layout)
@@ -1202,7 +1236,8 @@ def execute_sharded(job, bundle, scheduler, codec, rounds: int,
             u = jax.tree.map(
                 lambda p, g, e: p.astype(jnp.float32) - g[None] + e[lidx],
                 new_p, c["ref"], c["ef"])
-            vals = _qdq_tree(u, chunkw, align, codec.name)
+            with jax.named_scope("quantize"):
+                vals = _qdq_tree(u, chunkw, align, codec.name)
         else:
             vals = new_p
 
@@ -1213,20 +1248,22 @@ def execute_sharded(job, bundle, scheduler, codec, rounds: int,
         # padded slots gather a clipped throwaway row — zero them so the
         # fold (weight 0) can never be polluted by a non-finite garbage
         # value (0 · nan = nan)
-        flat, layout = engine.flatten(jax.tree.map(mask_pad, vals))
-        wk = w * valid.astype(jnp.float32)                      # [K]
-        onehot = (pod[None, :] == jnp.arange(num_pods)[:, None]
-                  ).astype(jnp.float32)                         # [P, K]
-        wp = onehot * wk[None, :]
-        # per-device partial pod sums; ONE O(P·N) psum crosses the mesh
-        pod_num = jax.lax.psum(jnp.einsum("pk,kn->pn", wp, flat), "site")
-        pod_tot = jax.lax.psum(jnp.sum(wp, axis=1), "site")
-        pod_mean = pod_num / (pod_tot[:, None] + 1e-12)
-        pod_w = ((pod_tot > 0).astype(jnp.float32) if inter == "uniform"
-                 else pod_tot)
-        gflat = jnp.einsum("p,pn->n", pod_w / (jnp.sum(pod_w) + 1e-12),
-                           pod_mean)
-        gtree = engine.unflatten(gflat, layout)
+        with jax.named_scope("fold"):
+            flat, layout = engine.flatten(jax.tree.map(mask_pad, vals))
+            wk = w * valid.astype(jnp.float32)                      # [K]
+            onehot = (pod[None, :] == jnp.arange(num_pods)[:, None]
+                      ).astype(jnp.float32)                         # [P, K]
+            wp = onehot * wk[None, :]
+            # per-device partial pod sums; ONE O(P·N) psum crosses the mesh
+            pod_num = jax.lax.psum(jnp.einsum("pk,kn->pn", wp, flat),
+                                   "site")
+            pod_tot = jax.lax.psum(jnp.sum(wp, axis=1), "site")
+            pod_mean = pod_num / (pod_tot[:, None] + 1e-12)
+            pod_w = ((pod_tot > 0).astype(jnp.float32) if inter == "uniform"
+                     else pod_tot)
+            gflat = jnp.einsum("p,pn->n", pod_w / (jnp.sum(pod_w) + 1e-12),
+                               pod_mean)
+            gtree = engine.unflatten(gflat, layout)
         c2 = {"round": c["round"] + 1}
         if quant:
             gbc = jax.tree.map(jnp.add, c["ref"], gtree)
@@ -1284,31 +1321,29 @@ def execute_sharded(job, bundle, scheduler, codec, rounds: int,
               "w": w_a[r], "pod": pod_a[r]}
         return jax.device_put(xs, row_shard)
 
+    runner = _ChunkRunner(step)
     recorder = job.recorder(rounds, num_sites)
-    xs0 = round_xs(0)
-    t0 = time.perf_counter()
-    compiled = jax.jit(step, donate_argnums=0).lower(carry, xs0).compile()
-    compile_s = time.perf_counter() - t0
-
     for r in range(rounds):
-        xs = xs0 if r == 0 else round_xs(r)
-        t0 = time.perf_counter()
-        carry, losses_dev = compiled(carry, xs)
-        jax.block_until_ready(losses_dev)
-        step_s = time.perf_counter() - t0
+        with recorder.chunk(r, 1):
+            with telemetry.span("repro.chunk.inputs") as inputs:
+                xs = round_xs(r)
+                inputs.set(bytes=_nbytes(xs))
+            carry, losses_dev, exec_s = runner.run(1, carry, xs)
 
-        def global_fn(c=carry):
-            return (c["ref"] if quant
-                    else global_mean(c["params"], w_all_dev))
+            def global_fn(c=carry):
+                return (c["ref"] if quant
+                        else global_mean(c["params"], w_all_dev))
 
-        on_grid = (recorder.store is not None
-                   and r % job.ckpt_every == 0) or r == rounds - 1
-        recorder.record(r, np.asarray(losses_dev)[:num_sites],
-                        participate[r],
-                        global_fn=global_fn if on_grid else None,
-                        extra={"step_s": step_s, "wall_s": step_s,
-                               "participants": int(participate[r].sum()),
-                               "k_cap": k_cap})
+            on_grid = (recorder.store is not None
+                       and r % job.ckpt_every == 0) or r == rounds - 1
+            with telemetry.span("repro.chunk.record"):
+                recorder.record(r, np.asarray(losses_dev)[:num_sites],
+                                participate[r],
+                                global_fn=global_fn if on_grid else None,
+                                extra={"step_s": exec_s,
+                                       "participants": int(
+                                           participate[r].sum()),
+                                       "k_cap": k_cap})
 
     uploads = int(participate.sum())
     if quant:
@@ -1345,7 +1380,7 @@ def execute_sharded(job, bundle, scheduler, codec, rounds: int,
              "round": carry["round"]}
     return recorder.result(global_params, transport="stacked",
                            scheduler=scheduler.name, state=state, comm=comm,
-                           compile_s=compile_s,
+                           compile_s=runner.compile_s,
                            privacy=job.privacy_report(rounds))
 
 

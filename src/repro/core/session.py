@@ -38,6 +38,7 @@ both schedulers is documented in ``docs/architecture.md``.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass
 from pathlib import Path
@@ -206,8 +207,12 @@ class JobResult:
     # stacked simulator — see benchmarks/comm_bytes.py); None when the
     # strategy has no measured exchange
     comm: Optional[Dict[str, Any]] = None
-    # jit compile time, measured once per program shape and kept OUT of
-    # the per-round ``step_s`` history (round 0 used to absorb it)
+    # seconds spent lowering and compiling each program shape (on the
+    # stacked engines the sum of the ``repro.chunk.lower`` and
+    # ``repro.chunk.compile`` spans), kept OUT of the per-round history:
+    # there ``step_s`` is a round's share of device execution and
+    # ``wall_s`` its share of the host's wall time less lowering and
+    # compiling (``RoundRecorder.chunk``)
     compile_s: float = 0.0
     # crash resume: the checkpoint round this run re-entered from
     # (None = started at round 0); history then covers only the rounds
@@ -292,6 +297,7 @@ class RoundRecorder:
             self.store = CheckpointStore(Path(checkpoint_dir))
         self._t0 = time.time()
         self._t_last = self._t0
+        self._chunk_start: Optional[int] = None
 
     @property
     def elapsed(self) -> float:
@@ -305,8 +311,10 @@ class RoundRecorder:
         loss = float(np.nanmean(per_site))
         n_active = int(np.sum(active))
         rec = {"round": round_index, "loss": loss, "active": n_active,
-               "per_site_loss": per_site.tolist(),
-               "wall_s": now - self._t_last, **(extra or {})}
+               "per_site_loss": per_site.tolist()}
+        if self._chunk_start is None:     # else RoundRecorder.chunk's share
+            rec["wall_s"] = now - self._t_last
+        rec.update(extra or {})
         self.history.append(rec)
         self._t_last = now
         if self.verbose and (round_index % self.log_every == 0
@@ -316,6 +324,27 @@ class RoundRecorder:
         if (self.store and global_fn is not None
                 and round_index % self.ckpt_every == 0):
             self.store.save("global", round_index, global_fn())
+
+    @contextlib.contextmanager
+    def chunk(self, first_round: int, rounds: int):
+        """One host iteration of a stacked engine, whose ``rounds`` rounds
+        run as one program and are recorded inside the block: the
+        ``repro.chunk`` span.  When it closes, each round recorded inside
+        takes an equal share of the span's wall time less the lowering
+        and compiling inside it (those go to ``compile_s``)."""
+        from repro import telemetry
+        self._chunk_start = len(self.history)
+        try:
+            with telemetry.span("repro.chunk", round=first_round,
+                                rounds=rounds) as span:
+                yield span
+        finally:
+            wall = span.seconds - span.inner("repro.chunk.lower",
+                                             "repro.chunk.compile")
+            for rec in self.history[self._chunk_start:]:
+                rec["wall_s"] = wall / rounds
+            self._chunk_start = None
+            self._t_last = time.time()
 
     def save_state(self, round_index: int, state_fn,
                    meta: Optional[Dict[str, Any]] = None):

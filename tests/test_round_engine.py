@@ -490,3 +490,77 @@ def test_sharded_unsupported_combos_raise():
     with pytest.raises(ValueError, match="shutdown"):
         _job(shard_sites=True, sample="uniform:2",
              dropout_scenario="disconnect").run()
+
+
+# ---------------------------------------------------------------------------
+# Host spans (repro.telemetry) and the per-round timing fields they give
+# ---------------------------------------------------------------------------
+
+SPAN_ENGINES = {"sync": dict(round_engine="scan"),
+                "compressed": dict(round_engine="scan", compression="int8"),
+                "buffered": dict(round_engine="scan",
+                                 scheduler=BufferedScheduler(buffer_k=2)),
+                "sharded": dict(shard_sites=True)}
+
+
+def _spans_named(name):
+    from repro import telemetry
+    return [s for s in telemetry.spans() if s.name == name]
+
+
+@pytest.mark.parametrize("engine", ["sync", "compressed", "buffered"])
+def test_chunk_spans_on_every_scan_engine(engine, monkeypatch):
+    """A 5-round job in chunks of 2 records one ``repro.chunk`` per
+    chunk, one lower and one compile per chunk length, and the bytes of
+    exactly the arrays handed to the chunk program."""
+    from repro.core import round_engine
+    handed = []
+    orig = round_engine._ChunkRunner.run
+
+    def run(self, kc, carry, xs):
+        handed.append(sum(x.nbytes for x in jax.tree.leaves(xs)))
+        return orig(self, kc, carry, xs)
+    monkeypatch.setattr(round_engine._ChunkRunner, "run", run)
+    res = _job(rounds=5, chunk_rounds=2, **SPAN_ENGINES[engine]).run()
+    chunks = _spans_named("repro.chunk")
+    assert [c.attrs["round"] for c in chunks] == [0, 2, 4]
+    assert [c.attrs["rounds"] for c in chunks] == [2, 2, 1]
+    ids = [c.id for c in chunks]
+    for name in ("repro.chunk.inputs", "repro.chunk.execute",
+                 "repro.chunk.record"):
+        assert [s.parent for s in _spans_named(name)] == ids, name
+    assert [s.attrs["bytes"] for s in
+            _spans_named("repro.chunk.inputs")] == handed
+    lower = _spans_named("repro.chunk.lower")
+    compile_ = _spans_named("repro.chunk.compile")
+    assert sorted(s.attrs["rounds"] for s in lower) == [1, 2]
+    assert sorted(s.attrs["rounds"] for s in compile_) == [1, 2]
+    assert res.compile_s == pytest.approx(
+        sum(s.seconds for s in lower + compile_), rel=1e-9)
+
+
+@pytest.mark.parametrize("engine", ["sync", "compressed", "buffered",
+                                    "sharded"])
+def test_wall_s_is_host_wall_time(engine):
+    """``wall_s`` is a round's share of its chunk's host wall time (the
+    ``repro.chunk`` span) less the lowering and compiling inside it, which
+    ``compile_s`` holds; ``step_s`` is its share of the execute span."""
+    kw = SPAN_ENGINES[engine]
+    res = _job(rounds=5, chunk_rounds=None if "shard_sites" in kw else 2,
+               **kw).run()
+    chunks = _spans_named("repro.chunk")
+    execs = _spans_named("repro.chunk.execute")
+    assert len(res.history) == 5
+    for h in res.history:
+        assert h["wall_s"] >= h["step_s"] > 0.0
+    assert sum(h["wall_s"] for h in res.history) + res.compile_s == (
+        pytest.approx(sum(c.seconds for c in chunks), rel=1e-9))
+    # the first chunk of each shape holds the compile, and its rounds'
+    # wall_s does not
+    assert res.compile_s > max(h["wall_s"] for h in res.history)
+    assert sum(h["step_s"] for h in res.history) == pytest.approx(
+        sum(e.seconds for e in execs), rel=1e-9)
+    if "shard_sites" in kw:
+        assert [c.attrs["rounds"] for c in chunks] == [1] * 5
+        assert [s.attrs["rounds"]
+                for s in _spans_named("repro.chunk.compile")] == [1]
