@@ -3,8 +3,10 @@
 One FL round (Figs 3/4, Algorithm 1):
 
   1. (decentralized) pre-exchange: receive peer model + regional DCML
-  2. local training: ``local_steps`` optimizer steps per site, vmapped
-     over the stacked site axis (each site sees only its own batch shard)
+  2. local training: ``local_steps`` optimizer steps per site over the
+     stacked site axis (each site sees only its own batch shard): one
+     site after another where the step holds a convolution, vmapped
+     otherwise (:func:`_over_sites`)
   3. (centralized) post-exchange: weighted aggregation + broadcast
   4. dropout semantics: "shutdown" sites skip (2); inactive sites always
      skip exchanges (their aggregation weight is zero and they keep
@@ -21,9 +23,11 @@ import dataclasses
 from typing import Any, Callable, Dict, Optional
 
 import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.configs.base import FederationConfig, JobConfig, MeshConfig
 from repro.core import stacking
 from repro.core.topology import FLAT, Topology
@@ -132,6 +136,52 @@ def make_round_inputs_traced(ctx: FLContext, key, active):
     return {"active": active, "partner": partner, "is_receiver": is_recv}
 
 
+def _has_conv(jaxpr) -> bool:
+    """Whether ``conv_general_dilated`` appears in ``jaxpr`` or in any
+    program nested in it (scan bodies, remat, custom derivative rules)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "conv_general_dilated":
+            return True
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)        # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns") and _has_conv(sub):
+                    return True
+    return False
+
+
+def _over_sites(per_site, *stacked):
+    """``per_site`` over the leading site axis of every ``stacked`` tree,
+    outputs stacked [S, …].  One site's step is traced once; where it
+    holds a convolution the sites run one after another (``lax.map``),
+    otherwise all at once (``vmap``).
+
+    Vmapping a convolution whose kernel is a site parameter makes it a
+    site-grouped one (``feature_group_count`` = S forward,
+    ``batch_group_count`` = S for the kernel's gradient).  A TPU has no
+    direct form for either: the compiler masks an S-fold dense kernel
+    gradient with an iota and turns the site axis into a fourth window
+    dimension, about 1.7× the work of S plain convolutions.  A
+    site-batched ``dot_general`` is native on the MXU, so conv-free
+    (token) models keep the vmap.  The choice is recorded as the
+    zero-length span ``repro.site_update``."""
+    one = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                       stacked)
+    closed, out_shape = jax.make_jaxpr(per_site, return_shape=True)(*one)
+    form = "map" if _has_conv(closed.jaxpr) else "vmap"
+    with telemetry.span("repro.site_update", form=form,
+                        sites=jax.tree.leaves(stacked)[0].shape[0]):
+        pass
+    out_tree = jax.tree.structure(out_shape)
+    step = jax_core.jaxpr_as_fun(closed)
+
+    def one_site(*args):
+        return jax.tree.unflatten(out_tree, step(*jax.tree.leaves(args)))
+    if form == "map":
+        return jax.lax.map(lambda xs: one_site(*xs), stacked)
+    return jax.vmap(one_site)(*stacked)
+
+
 def build_fl_round(ctx: FLContext, remat_local: bool = False):
     """Returns ``fl_round(fl_state, batches, round_inputs) -> (fl_state, metrics)``.
 
@@ -230,9 +280,9 @@ def build_fl_round(ctx: FLContext, remat_local: bool = False):
                     (site_batches, jnp.arange(k, dtype=jnp.int32)))
                 return params, opt, jax.tree.map(lambda x: x[-1], ms)
 
-            new_params, new_opt, metrics = jax.vmap(
-                per_site_dp, in_axes=(0, 0, 0, 0))(
-                fl_state["params"], fl_state["opt"], batches, site_ids)
+            new_params, new_opt, metrics = _over_sites(
+                per_site_dp, fl_state["params"], fl_state["opt"], batches,
+                site_ids)
         else:
             def per_site(params, opt, site_batches):
                 def body(carry, b):
@@ -243,9 +293,8 @@ def build_fl_round(ctx: FLContext, remat_local: bool = False):
                                                  site_batches)
                 return params, opt, jax.tree.map(lambda x: x[-1], ms)
 
-            new_params, new_opt, metrics = jax.vmap(
-                per_site, in_axes=(0, 0, 0))(fl_state["params"],
-                                             fl_state["opt"], batches)
+            new_params, new_opt, metrics = _over_sites(
+                per_site, fl_state["params"], fl_state["opt"], batches)
 
         if ctx.fed.dropout_scenario == "shutdown":
             # workstation off: inactive sites neither train nor update state
