@@ -26,6 +26,14 @@ import numpy as np
 DEAD_LEAF = 1e-3
 
 
+def path_norms(tree) -> Dict[str, float]:
+    """``{leaf path: L2 norm}`` in float64."""
+    import jax
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {jax.tree_util.keystr(path): float(np.linalg.norm(
+        np.asarray(x, np.float64))) for path, x in flat}
+
+
 def leaf_gaps(got: np.ndarray, want: np.ndarray) -> np.ndarray:
     """Per-leaf gap of norms (last axis: leaves), each against the
     reference's norm of that leaf or the median leaf's, the larger."""

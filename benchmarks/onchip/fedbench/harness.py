@@ -18,8 +18,11 @@ the recorder the job returns from ``recorder()``:
 
 Once the window has closed and the device's peak memory has been read,
 the job's state is gone and the reference re-runs the first rounds from
-the same seed on the same cases; the numbers compared are in
-:mod:`fedbench.compare`.
+the same seed on the same cases, on the first chip alone whatever the
+cell's chip count (its site step then compiles once); the numbers
+compared are in :mod:`fedbench.compare`.  Everything that depends on the
+model comes from the configuration's family (``families/<family>.py``,
+:func:`fedbench.spec.family`).
 """
 from __future__ import annotations
 
@@ -38,7 +41,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from fedbench import compare, counts, reference, spec
+from fedbench import compare, spec
 
 PROCESS_T0 = time.perf_counter()
 CACHE_DIR = spec.ROOT / ".jax_cache"
@@ -76,10 +79,13 @@ def chip_devices(chips: int):
 def enable_cache(cache_dir: Path = CACHE_DIR) -> None:
     """JAX's persistent compilation cache at a fixed path in the checkout,
     whatever ``JAX_COMPILATION_CACHE_DIR`` says: only the checkout is the
-    run's own, and the path is part of the cache's key."""
+    run's own, and the path is part of the cache's key.  No size limit:
+    one set by ``JAX_COMPILATION_CACHE_MAX_SIZE`` refuses every entry
+    larger than itself, and a cell's programs are."""
     import jax
     cache_dir.mkdir(parents=True, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    jax.config.update("jax_compilation_cache_max_size", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
@@ -132,32 +138,6 @@ def seeds(seed: int) -> Dict[str, Any]:
     words = np.random.SeedSequence(int(seed)).generate_state(4)
     return {"pool_key": words[:2], "draw": int(words[2]),
             "job": int(words[3] & 0x7FFFFFFF)}
-
-
-def task_fields(cfg: dict, traffic: dict, job_seed: int) -> Dict[str, Any]:
-    """``TaskConfig`` fields for the configuration and traffic."""
-    f = dict(sites=traffic["sites"], batch=traffic["batch"],
-             volume=tuple(cfg["volume"]), base_filters=cfg["base_filters"],
-             num_levels=cfg["num_levels"], seed=job_seed)
-    if cfg["task"] == "dose":
-        f.update(kind="dose", num_oars=cfg["in_channels"] - 2)
-    else:
-        f.update(kind="seg", in_channels=cfg["in_channels"],
-                 num_classes=cfg["out_channels"])
-    return f
-
-
-def check_model(cfg: dict, model_cfg) -> None:
-    """The program builds the configuration as the file states it."""
-    want = {"in_channels": cfg["in_channels"],
-            "out_channels": cfg["out_channels"],
-            "base_filters": cfg["base_filters"],
-            "num_levels": cfg["num_levels"], "se_ratio": cfg["se_ratio"],
-            "deep_supervision": True}
-    got = {k: getattr(model_cfg, k) for k in want}
-    if got != want:
-        raise ValueError(f"program model {got} is not the configuration "
-                         f"{want}")
 
 
 class Window:
@@ -283,7 +263,8 @@ def build_job(cell: spec.Cell, pool, job_seed: int, rounds: int,
     cfg, tr = cell.config, cell.traffic
     opt = cfg["optimizer"]
     sharded = tr["engine"] == "sharded"
-    task = PoolTask(pool=pool, **task_fields(cfg, tr, job_seed))
+    task = PoolTask(pool=pool,
+                    **cell.family.task_fields(cfg, tr, job_seed))
     return TimedJob(
         task=task, strategy=tr["strategy"], rounds=rounds,
         local_steps=tr["local_steps"], lr=opt["lr"],
@@ -311,7 +292,7 @@ def program_numbers(win: Window, cell: spec.Cell, init) -> Dict[str, Any]:
         glob = _tree_map(lambda x: np.tensordot(w / w.sum(), np.asarray(
             x, np.float64), axes=1), win.state["params"])
     if glob is not None:
-        out["change"] = reference.path_norms(_tree_map(
+        out["change"] = compare.path_norms(_tree_map(
             lambda g, i: np.asarray(g, np.float64) - np.asarray(i, np.float64),
             glob, init))
     return out
@@ -333,16 +314,16 @@ def aligned(got: Dict[str, np.ndarray], want: Dict[str, np.ndarray]):
 
 
 def reference_numbers(cell: spec.Cell, pool, job_seed: int, rounds: int,
-                      precision: str, devices=None) -> Dict[str, Any]:
-    """The reference's first ``rounds`` rounds on the cell's cases, its
-    sites spread over ``devices``."""
+                      precision: str, device) -> Dict[str, Any]:
+    """The reference's first ``rounds`` rounds on the cell's cases, every
+    site on ``device``."""
     import jax
     tr = cell.traffic
     batch_fn = lambda s, step: pool.sample(s, step, tr["batch"])
-    return reference.run_rounds(
+    return cell.family.run_reference(
         cell.config, cell.config["optimizer"], tr["site_cases"], batch_fn,
         jax.random.PRNGKey(job_seed), rounds, tr["local_steps"], precision,
-        devices)
+        device)
 
 
 def numbers_compared(got: Dict[str, Any], ref: Dict[str, Any]
@@ -407,10 +388,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     rounds = win.warm + chunk * math.ceil(seconds * MAX_ROUNDS_PER_S / chunk)
     compiled: List[Any] = []
     with jax.default_matmul_precision(cfg["matmul_precision"]):
-        from fedbench.pool import CasePool
-        pool = CasePool(cfg, tr["site_cases"], s["pool_key"], s["draw"])
+        pool = cell.family.make_pool(cfg, tr["site_cases"], s["pool_key"],
+                                     s["draw"])
         job = build_job(cell, pool, s["job"], rounds, win)
-        check_model(cfg, job.task.model_config())
+        cell.family.check_model(cfg, job.task.model_config())
         try:
             with captured_compiles(compiled):
                 job.run()
@@ -437,11 +418,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         reading = Reading(trace=t, cell=cell, rounds=win.rounds,
                           window_s=tracing.window_ns(t) / 1e9,
                           chips=len(devices), peaks=peaks,
-                          round_flops=counts.round_flops(cfg, tr),
-                          num_params=counts.num_params(cfg))
+                          round_flops=cell.family.round_flops(cfg, tr),
+                          num_params=cell.family.num_params(cfg))
 
     t_ref = time.perf_counter()
-    ref = reference_numbers(cell, pool, s["job"], first, "highest", devices)
+    ref = reference_numbers(cell, pool, s["job"], first, "highest",
+                            devices[0])
     log(f"reference {time.perf_counter() - t_ref:.3f} s (first step, "
         f"compile included, {ref['seconds'][0]:.3f} s)")
     prog = program_numbers(win, cell, ref["init"])

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fedbench.compare import path_norms
+
 DIMNUMS = ("NDHWC", "DHWIO", "NDHWC")
 GROUPS = 8
 GN_EPS = 1e-5
@@ -276,13 +278,6 @@ def site_step(params, mu, nu, step, batch, cfg: dict, opt: dict, ar: Arith):
     return params, mu, nu, loss, g
 
 
-def path_norms(tree) -> Dict[str, float]:
-    """``{leaf path: L2 norm}`` in float64."""
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {jax.tree_util.keystr(path): float(np.linalg.norm(
-        np.asarray(x, np.float64))) for path, x in flat}
-
-
 @functools.lru_cache(maxsize=8)
 def _programs(cfg_json: str, opt_json: str, precision: str):
     """The jitted site step and initialiser, one pair per configuration
@@ -296,24 +291,24 @@ def _programs(cfg_json: str, opt_json: str, precision: str):
 def run_rounds(cfg: dict, opt: dict, site_cases: List[int],
                batch_fn: Callable[[int, int], Dict[str, np.ndarray]],
                init_key, rounds: int, local_steps: int,
-               precision: str, devices=None) -> Dict[str, object]:
-    """Drive ``rounds`` federated rounds of the reference, one site at a
-    time (site ``s`` on ``devices[s % len(devices)]``, the fold on the
-    first).  ``batch_fn(site, step)`` gives the site's ``[B, …]`` batch.
+               precision: str, device) -> Dict[str, object]:
+    """Drive ``rounds`` federated rounds of the reference on ``device``,
+    one site after another, the fold there too, so that its site step
+    compiles once whatever the cell's chip count.  ``batch_fn(site,
+    step)`` gives the site's ``[B, …]`` batch.
 
     Returns the per-round per-site losses ``[rounds, S]``, the initial
     weights (host), and per leaf path: the norm of the global's change
     from them, and per site the norm of the first clipped gradient."""
     t0, first_call = time.perf_counter(), None
-    devices = list(devices or jax.devices()[:1])
     step, init_fn = _programs(json.dumps(cfg, sort_keys=True),
                               json.dumps(opt, sort_keys=True), precision)
-    init = jax.device_put(init_fn(init_key), devices[0])
+    init = jax.device_put(init_fn(init_key), device)
     sites = len(site_cases)
-    on = [devices[s % len(devices)] for s in range(sites)]
     w = np.asarray(site_cases, np.float64)
     w = jnp.asarray(w / w.sum(), jnp.float32)
-    mus = [jax.device_put(jax.tree.map(jnp.zeros_like, init), d) for d in on]
+    mus = [jax.device_put(jax.tree.map(jnp.zeros_like, init), device)
+           for _ in range(sites)]
     nus = list(mus)
     losses = [[None] * sites for _ in range(rounds)]
     grad0 = [None] * sites
@@ -321,17 +316,17 @@ def run_rounds(cfg: dict, opt: dict, site_cases: List[int],
     for r in range(rounds):
         new = []
         for s in range(sites):
-            p = jax.device_put(glob, on[s])
+            p = glob
             for k in range(local_steps):
                 t = jnp.asarray(r * local_steps + k, jnp.int32)
-                b = jax.device_put(batch_fn(s, r * local_steps + k), on[s])
+                b = jax.device_put(batch_fn(s, r * local_steps + k), device)
                 p, mus[s], nus[s], loss, g = step(p, mus[s], nus[s], t, b)
                 if first_call is None:
                     first_call = time.perf_counter() - t0
                 if r == 0 and k == 0:
                     grad0[s] = g
             losses[r][s] = loss
-            new.append(jax.device_put(p, devices[0]))
+            new.append(p)
         if r == 0:
             grad0 = [path_norms(g) for g in grad0]
         wsum = jnp.sum(w) + FOLD_EPS

@@ -1,6 +1,7 @@
 """conv_ms_per_round (ms): device time of the convolution operations per
 round, averaged over the chips, classified by the HLO category the trace
-carries with each operation.  Layer: site update."""
+carries with each operation.  A trace with no convolution gives no value.
+Layer: site update."""
 from fedbench import tracing
 
 
@@ -9,9 +10,8 @@ def is_conv(op):
 
 
 def read(r):
-    if r.rounds == 0 or not any(op.category for c in r.trace.chips
-                                for op in r.trace.ops[c]):
+    per_chip = [tracing.op_time_ns(r.trace, c, is_conv)
+                for c in r.trace.chips]
+    if r.rounds == 0 or not any(calls for _, calls in per_chip):
         return None
-    total = sum(tracing.op_time_ns(r.trace, c, is_conv)[0]
-                for c in r.trace.chips) / len(r.trace.chips)
-    return total / 1e6 / r.rounds
+    return sum(t for t, _ in per_chip) / len(per_chip) / 1e6 / r.rounds

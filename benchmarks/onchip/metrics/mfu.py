@@ -1,7 +1,9 @@
 """mfu (%): model FLOPs of the rounds completed in the traced window over
-the window, the chips and the chip's peak.  The FLOPs are the SA-Net
-convolutions a training step needs (fedbench.counts), every site, every
-local step; the peak is the bf16 one from peaks.json.  Layer: round step."""
+the window, the chips and the chip's peak.  The FLOPs of one round are
+the configuration's model family's count (``round_flops`` of
+``families/<family>.py``: every site, every local step, forward and
+backward, recomputation not counted); the peak is the bf16 one from
+peaks.json.  Layer: round step."""
 
 
 def read(r):

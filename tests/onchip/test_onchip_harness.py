@@ -121,6 +121,30 @@ def test_trace_reduction_on_a_chip_trace():
     assert any("fedagg" in o.name for o in ops)
 
 
+def test_conv_reader_on_a_synthetic_trace():
+    """Device time of convolution ops per round, averaged over the chips
+    and clipped to the window; nothing where no chip ran one."""
+    op = tracing.Op
+    t = tracing.Trace(window=(0.0, 1e9))
+    t.ops = {0: [op("fusion.1", 1e6, 2e6, "convolution fusion"),
+                 op("all-reduce.3", 4e6, 5e6, "all-reduce"),
+                 op("convolution.2", 999.5e6, 1e6, "convolution")],
+             1: [op("fusion.1", 1e6, 4e6, "convolution fusion"),
+                 op("copy.7", 6e6, 1e6, "data formatting")]}
+    read = spec.metric_reader("conv_ms_per_round")
+    cell = tiny_cell("dose-s4-v56")
+
+    def reading(trace):
+        return harness.Reading(trace=trace, cell=cell, rounds=2,
+                               window_s=1.0, chips=len(trace.chips),
+                               peaks=None, round_flops=0, num_params=0)
+    # chip 0: 2 ms + the 0.5 ms inside the window; chip 1: 4 ms
+    assert read(reading(t)) == pytest.approx((2.5 + 4.0) / 2 / 2)
+    for ops in t.ops.values():
+        ops[:] = [o for o in ops if "convolution" not in o.category]
+    assert read(reading(t)) is None
+
+
 def test_per_layer_readers_on_a_chip_trace():
     t = tracing.load(str(TRACE))
     cell = tiny_cell("dose-s4-v56")
@@ -182,6 +206,91 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
     cell = spec.resolve(bench, "dose-s2-v56", bench_dir=bench_dir)
     assert cell.traffic["sites"] == 2
     assert {p: p.read_bytes() for p in before} == before
+
+
+TOY_FAMILY = '''"""A model family of the test's own: SA-Net's, each call counted."""
+import importlib.util
+from pathlib import Path
+
+_spec = importlib.util.spec_from_file_location(
+    "toy_family_sanet", Path(__file__).with_name("sanet.py"))
+sanet = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(sanet)
+calls = []
+
+
+def _counted(name):
+    fn = getattr(sanet, name)
+
+    def counted(*a, **kw):
+        calls.append(name)
+        return fn(*a, **kw)
+    return counted
+
+
+task_fields, check_model, make_pool, run_reference, round_flops, \\
+    num_params = map(_counted, ["task_fields", "check_model", "make_pool",
+                                "run_reference", "round_flops",
+                                "num_params"])
+'''
+
+
+def copied_bench(tmp_path):
+    """This benchmark's files under ``tmp_path`` as in a checkout, and
+    every file's bytes as copied."""
+    bench_dir = tmp_path / "benchmarks" / "onchip"
+    shutil.copytree(BENCH, bench_dir,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return bench_dir, {p: p.read_bytes() for p in bench_dir.rglob("*")
+                       if p.is_file()}
+
+
+def test_a_new_family_needs_only_new_files(tmp_path):
+    import jax
+    bench_dir, before = copied_bench(tmp_path)
+    (bench_dir / "families" / "toy.py").write_text(TOY_FAMILY)
+    config = dict(spec.load_json(bench_dir / "configs"
+                                 / "sanet-dose-openkbp.json"),
+                  name="toy-dose", family="toy", **TINY)
+    (bench_dir / "configs" / "toy-dose.json").write_text(json.dumps(config))
+    traffic = dict(spec.load_json(bench_dir / "traffic"
+                                  / "openkbp-noniid-s4.json"),
+                   name="toy-s2", sites=2, site_cases=[5, 3])
+    (bench_dir / "traffic" / "toy-s2.json").write_text(json.dumps(traffic))
+    (bench_dir / "limits" / "toy-s2-v8.json").write_text(json.dumps(
+        spec.load_json(bench_dir / "limits" / "dose-s4-v56.json")))
+    bench = spec.load_benchmark()
+    bench["configs"].append({"name": "toy-dose", "source": "a test",
+                             "file": "benchmarks/onchip/configs/toy-dose.json",
+                             "reduced": [], "why": "a family of its own"})
+    bench["workloads"].append({"name": "toy-s2-v8", "config": "toy-dose",
+                               "traffic": "toy-s2", "chips": 1,
+                               "why": "two sites"})
+    assert spec.check_names(bench) == []
+    cell = spec.resolve(bench, "toy-s2-v8", bench_dir=bench_dir)
+    res = harness.run_cell(cell, 2 ** 35 + 1, 0.3, False, jax.devices()[:1])
+    assert res["correct"] is True, res["compared"]
+    assert {"task_fields", "check_model", "make_pool",
+            "run_reference"} <= set(cell.family.calls)
+    assert {p: p.read_bytes() for p in before} == before
+
+
+def test_a_config_without_a_family_is_refused(tmp_path):
+    bench_dir, _ = copied_bench(tmp_path)
+    config = spec.load_json(bench_dir / "configs" / "sanet-dose-openkbp.json")
+    del config["family"]
+    (bench_dir / "configs" / "no-family.json").write_text(json.dumps(config))
+    bench = spec.load_benchmark()
+    bench["configs"].append({"name": "no-family", "source": "a test",
+                             "file": "benchmarks/onchip/configs/no-family.json",
+                             "reduced": [], "why": "no family"})
+    bench["workloads"].append({"name": "no-family-s4", "config": "no-family",
+                               "traffic": "openkbp-noniid-s4", "chips": 1,
+                               "why": "no family"})
+    with pytest.raises(ValueError, match="names no model family"):
+        spec.resolve(bench, "no-family-s4", bench_dir=bench_dir)
+    with pytest.raises(ValueError, match="name rules"):
+        spec.family("../families/sanet", bench_dir)
 
 
 def test_run_refuses_without_a_tpu():
@@ -270,13 +379,25 @@ SHARDED = """
 import json, sys
 sys.path.insert(0, {bench!r}); sys.path.insert(0, {src!r})
 import jax
-from fedbench import harness, spec
+from fedbench import harness, reference, spec
 cell = spec.resolve(spec.load_benchmark(), "dose-s4-v56")
 cell.name = "dose-s8-v56-shard4"
 cell.traffic = spec.load_json(spec.BENCH_DIR / "traffic"
                               / "openkbp-noniid-s8-shard4.json")
 cell.chips = 4
 cell.config.update({tiny!r})
+run_reference, site_step = cell.family.run_reference, reference.site_step
+ref_devices, traces = [], []
+
+def recorded(*a):
+    ref_devices.append(str(a[-1]))
+    return run_reference(*a)
+
+def counted(*a, **kw):
+    traces.append(1)
+    return site_step(*a, **kw)
+
+cell.family.run_reference, reference.site_step = recorded, counted
 out = {{}}
 for name in ("sound", "exchange_left_out"):
     if name == "exchange_left_out":
@@ -284,16 +405,23 @@ for name in ("sound", "exchange_left_out"):
     res = harness.run_cell(cell, 77, 0.3, False, jax.devices())
     out[name] = {{"correct": res["correct"], "compared": res["compared"],
                  "count": res["device"]["count"]}}
+step, _ = reference._programs(json.dumps(cell.config, sort_keys=True),
+                              json.dumps(cell.config["optimizer"],
+                                         sort_keys=True), "highest")
+out["reference"] = {{"devices": ref_devices, "traces": len(traces),
+                    "compiles": step._cache_size(),
+                    "first": str(jax.devices()[0])}}
 print(json.dumps(out))
 """
 
 
 @pytest.fixture(scope="module")
 def sharded_runs():
-    """The 8-site sharded traffic (the four-chip cell this benchmark
-    leaves for a later PR) on four virtual CPU devices, sound and with
-    the fold's exchange between devices left out, in one child process
-    (the device count is fixed when JAX starts)."""
+    """The 8-site sharded traffic, which no cell runs yet, at a tiny width
+    on four virtual CPU devices, sound and with the fold's exchange between
+    devices left out, in one child process (the device count is fixed
+    when JAX starts), recording the reference's devices and how often its
+    site step was traced and compiled."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     code = SHARDED.format(bench=str(BENCH), src=str(ROOT / "src"), tiny=TINY)
@@ -310,16 +438,26 @@ def test_sharded_cell_on_four_virtual_devices(name, sharded_runs):
     assert res["correct"] is (name == "sound"), res["compared"]
 
 
+def test_sharded_reference_compiles_once(sharded_runs):
+    """The reference runs every site on the first chip: one device per
+    run, its site step traced and compiled once over both runs."""
+    ref = sharded_runs["reference"]
+    assert ref["devices"] == [ref["first"]] * 2
+    assert ref["traces"] == 1 and ref["compiles"] == 1
+
+
 @pytest.mark.parametrize("name", ["dose-s4-v56", "seg-s4-v56"])
 def test_lower_precision_fails_the_limits(name):
     """A bfloat16 reference in the program's place, against the float32
     one on the same cases, at a size a test run holds."""
+    import jax
     from fedbench.pool import CasePool
     cell = tiny_cell(name)
     s = harness.seeds(2 ** 34 + 3)
     tr = cell.traffic
     pool = CasePool(cell.config, tr["site_cases"], s["pool_key"], s["draw"])
-    ref = harness.reference_numbers(cell, pool, s["job"], 3, "highest")
-    low = harness.reference_numbers(cell, pool, s["job"], 3, "bf16")
+    dev = jax.devices()[0]
+    ref = harness.reference_numbers(cell, pool, s["job"], 3, "highest", dev)
+    low = harness.reference_numbers(cell, pool, s["job"], 3, "bf16", dev)
     numbers = harness.numbers_compared(low, ref)
     assert not compare.all_within(compare.judge(numbers, cell.limits))
